@@ -20,7 +20,9 @@ Two runtimes share the stack:
 
 ``--parity`` runs both runtimes back to back and exits nonzero with a
 per-request token diff unless the output is token-exact — the acceptance
-check the CI smoke jobs enforce.
+check the CI smoke jobs enforce. The single-process reference runs in a
+child process that exits before the workers start, so the parent never
+holds a device the workers need.
 
   PYTHONPATH=src python examples/serve_disagg.py [--requests 24]
   PYTHONPATH=src python examples/serve_disagg.py --two-process --connector shm
@@ -29,6 +31,7 @@ check the CI smoke jobs enforce.
   PYTHONPATH=src python examples/serve_disagg.py --plan --connector shm
 """
 import argparse
+import collections
 import sys
 import time
 
@@ -65,6 +68,9 @@ def build_requests(n: int, max_new: int):
 
 def run_single(args, faults: bool):
     """Single-process runtime: all engines in this process."""
+    from repro.serving.jit_cache import enable_jit_cache
+    enable_jit_cache()                    # before the first compile
+
     import jax
 
     from repro.core.disagg import DisaggPipeline
@@ -73,6 +79,8 @@ def run_single(args, faults: bool):
     from repro.serving.scheduler import GlobalScheduler
     from repro.serving.server import Server
 
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} ({dev.device_kind})")
     n = sum(int(np.prod(p.shape)) for p in
             jax.tree.leaves(M.abstract_params(CFG)))
     print(f"model: {CFG.name} ({n/1e6:.0f}M params)")
@@ -126,7 +134,8 @@ def run_single(args, faults: bool):
     total_tokens = sum(len(r.output_tokens) for r in done)
     print(f"\nfinished {len(done)}/{len(reqs)} requests, "
           f"{total_tokens} tokens in {wall:.1f}s "
-          f"({total_tokens / wall:.0f} tok/s on CPU)")
+          f"({total_tokens / wall:.0f} tok/s on {dev.platform} "
+          f"{dev.device_kind})")
     print(f"requeues after failure: {sched.stats.requeues}")
     print(f"P dispatches: {dict(sched.stats.p_dispatches)}")
     print(f"D dispatches: {dict(sched.stats.d_dispatches)}")
@@ -206,13 +215,25 @@ def run_cluster(args):
     total_tokens = sum(len(t) for t in tokens.values())
     print(f"\nfinished {rt.stats.finished}/{len(reqs)} requests, "
           f"{total_tokens} tokens in {wall:.1f}s "
-          f"({total_tokens / wall:.0f} tok/s on CPU)")
+          f"({total_tokens / wall:.0f} tok/s on "
+          f"{_devices_label(rt.worker_devices)})")
     print(f"worker pids: {rt.worker_pids} (parent {os.getpid()})")
     _print_wire(rt.transfer_stats)
     print()
     print(format_report(plan_vs_measured(rt, reqs, plan=plan, wall_s=wall)))
     assert rt.stats.finished == len(reqs), "lost requests!"
     return tokens
+
+
+def _devices_label(devices) -> str:
+    """The devices the workers computed on, e.g. '4 × tpu TPU v5 lite'."""
+    kinds = collections.Counter(f"{d['platform']} {d['kind']}"
+                                for d in devices.values())
+    return ", ".join(f"{n} × {k}" for k, n in sorted(kinds.items()))
+
+
+def _single_child(args, out) -> None:
+    out.put(run_single(args, faults=False))
 
 
 def _print_wire(ts) -> None:
@@ -303,8 +324,10 @@ def main():
                  or args.num_p is not None or args.num_d is not None)
 
     if args.parity:
-        print("== parity: single-process reference ==")
-        ref = run_single(args, faults=False)
+        print("== parity: single-process reference (child process) ==",
+              flush=True)
+        from repro.serving.multiproc.chips import run_in_child
+        ref = run_in_child(_single_child, args)
         print("\n== parity: multi-process runtime ==")
         got = run_cluster(args)
         bad = _parity_diff(ref, got)

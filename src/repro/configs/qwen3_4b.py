@@ -1,4 +1,5 @@
-"""Qwen3-4B — dense, GQA kv=8, per-head qk-norm. [hf:Qwen/Qwen3-8B; hf]"""
+"""Qwen3-4B — dense, GQA kv=8, per-head qk-norm, tied embeddings.
+[hf:Qwen/Qwen3-4B config.json]"""
 from repro.configs.base import ModelConfig, register
 
 CONFIG = register(ModelConfig(
@@ -13,5 +14,6 @@ CONFIG = register(ModelConfig(
     vocab_size=151936,
     qk_norm=True,
     rope_theta=1_000_000.0,
-    source="hf:Qwen/Qwen3-8B",
+    tie_embeddings=True,
+    source="hf:Qwen/Qwen3-4B",
 ))

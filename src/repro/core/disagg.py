@@ -61,7 +61,7 @@ def _to_device(payload):
 
 def _repage_pool_body(spec: PC.KVPageSpec, pool: jax.Array, block_ids,
                       canon: jax.Array, lo_block, *, front: int, rmw: bool,
-                      kernel: bool) -> jax.Array:
+                      kernel: bool, interpret: bool = False) -> jax.Array:
     """Single-pass re-page of canon (count, S, kv, hd) landing ``front``
     rows into block ``lo_block``'s first page, vmapped over the layer
     count. ``lo_block`` is *traced* and ``front`` (= start % block_size)
@@ -72,7 +72,8 @@ def _repage_pool_body(spec: PC.KVPageSpec, pool: jax.Array, block_ids,
     Unlike the legacy rmw path — which reads back *every* touched page
     and splices — the overlay scatter only reads the first/last partial
     page (jnp path) or merges partial rows inside the Pallas kernel
-    (``kernel=True``), so interior pages move exactly once."""
+    (``kernel=True``), so interior pages move exactly once. ``interpret``
+    runs that kernel in Pallas interpret mode (CPU tests)."""
     bs = spec.block_size
     s = canon.shape[1]
     s_tot = front + s
@@ -84,26 +85,36 @@ def _repage_pool_body(spec: PC.KVPageSpec, pool: jax.Array, block_ids,
         return jax.vmap(lambda pl, cn: PC.scatter_sequence(spec, pl, use, cn)
                         )(pool, canon)
     if kernel:
+        # one kernel call for every layer: the layer axis folds into the
+        # page axis (layer l's pages sit at l·N + id), so the aliased pool
+        # stays one unbatched array — a vmapped call would give it a batch
+        # block the TPU lowering refuses
+        count, n = pool.shape[0], pool.shape[1]
         cp = jnp.pad(canon, ((0, 0), (front, nb * bs - s_tot),
                              (0, 0), (0, 0)))
-        cp = cp.reshape(canon.shape[0], nb, bs, spec.kv_heads, spec.head_dim)
-        return jax.vmap(lambda pl, cn: kops.scatter_pages_overlay(
-            spec, pl, use, cn, front=front, seq_len=s))(pool, cp)
+        cp = cp.reshape(count * nb, bs, spec.kv_heads, spec.head_dim)
+        ids = (jnp.arange(count, dtype=use.dtype)[:, None] * n
+               + use[None]).reshape(-1)
+        out = kops.scatter_pages_overlay(
+            spec, pool.reshape((count * n,) + pool.shape[2:]), ids, cp,
+            front=front, seq_len=s, span=nb, force_interpret=interpret)
+        return out.reshape(pool.shape)
     return jax.vmap(lambda pl, cn: PC.scatter_sequence_overlay(
         spec, pl, use, cn, front))(pool, canon)
 
 
 _repage_pool = jax.jit(_repage_pool_body,
-                       static_argnames=("spec", "front", "rmw", "kernel"))
+                       static_argnames=("spec", "front", "rmw", "kernel",
+                                        "interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "wire", "tp_p", "tp_d",
                                              "count", "front", "rmw",
-                                             "kernel"))
+                                             "kernel", "interpret"))
 def _repage_kv_entry(spec: PC.KVPageSpec, k_pool: jax.Array,
                      v_pool: jax.Array, block_ids, pay, sc, lo_block, *,
                      wire: WireFormat, tp_p: int, tp_d: int, count: int,
-                     front: int, rmw: bool, kernel: bool):
+                     front: int, rmw: bool, kernel: bool, interpret: bool):
     """One compiled program per (chunk shape, in-page offset): dequantize
     the whole shard-major slab (2·tp_p, count, S, kvs, hd) in one pass,
     realign TP shards, overlay-scatter both pools. The landing block
@@ -120,21 +131,26 @@ def _repage_kv_entry(spec: PC.KVPageSpec, k_pool: jax.Array,
         parallel_align.realign_shards(list(dec[tp_p:]), tp_d),
         axis=1).reshape(count, s, -1, spec.head_dim)
     return (_repage_pool_body(spec, k_pool, block_ids, k_d, lo_block,
-                              front=front, rmw=rmw, kernel=kernel),
+                              front=front, rmw=rmw, kernel=kernel,
+                              interpret=interpret),
             _repage_pool_body(spec, v_pool, block_ids, v_d, lo_block,
-                              front=front, rmw=rmw, kernel=kernel))
+                              front=front, rmw=rmw, kernel=kernel,
+                              interpret=interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "wire", "count",
-                                             "front", "rmw", "kernel"))
+                                             "front", "rmw", "kernel",
+                                             "interpret"))
 def _repage_mla_part(spec: PC.KVPageSpec, pool: jax.Array, block_ids,
                      pay, sc, lo_block, *, wire: WireFormat, count: int,
-                     front: int, rmw: bool, kernel: bool) -> jax.Array:
+                     front: int, rmw: bool, kernel: bool,
+                     interpret: bool) -> jax.Array:
     sc_j = None if sc is None else sc.reshape(pay.shape[0], 1, 1)
     d = precision.decode_wire(pay, sc_j, wire, spec.jdtype)
     d = d.reshape(count, -1, 1, spec.head_dim)
     return _repage_pool_body(spec, pool, block_ids, d, lo_block,
-                             front=front, rmw=rmw, kernel=kernel)
+                             front=front, rmw=rmw, kernel=kernel,
+                             interpret=interpret)
 
 
 # chunk wire codecs: "fixed" stages zero-copy WireChunks (fixed binary
@@ -146,7 +162,8 @@ CODECS = ("fixed", "pickle")
 class DisaggPipeline:
     def __init__(self, transfer: KVConnector,
                  wire: Optional[WireFormat] = None,
-                 codec: str = "fixed", repage_kernel: bool = False):
+                 codec: str = "fixed", repage_kernel: bool = False,
+                 kernel_interpret: bool = False):
         assert codec in CODECS, codec
         self.transfer = transfer
         self.wire = wire or WireFormat(kind="raw", dtype="bfloat16")
@@ -154,6 +171,9 @@ class DisaggPipeline:
         # route the chunk re-page scatter through the Pallas overlay kernel
         # (partial blocks merge inside the kernel) instead of the jnp path
         self.repage_kernel = repage_kernel
+        # run that kernel in Pallas interpret mode (CPU tests); otherwise
+        # it compiles for the TPU and any other backend is an error
+        self.kernel_interpret = kernel_interpret
 
     # ------------------------------------------------------------------ #
     # P side: package → wire
@@ -315,6 +335,7 @@ class DisaggPipeline:
         caches = [list(g) for g in d_engine.caches]
         bids = jnp.asarray(block_ids, jnp.int32)
         kernel = self.repage_kernel
+        interpret = self.kernel_interpret
 
         for entry in chunk.entries():
             gi, pi = entry["gi"], entry["pi"]
@@ -331,7 +352,7 @@ class DisaggPipeline:
                         None if sc is None else jnp.array(sc),
                         start // spec_m.block_size, wire=wire, count=count,
                         front=start % spec_m.block_size, rmw=rmw,
-                        kernel=kernel)
+                        kernel=kernel, interpret=interpret)
                 caches[gi][pi] = dict(pools, **new)
                 continue
             spec = d_engine.specs["kv"]
@@ -345,7 +366,8 @@ class DisaggPipeline:
                 None if sc is None else jnp.array(sc),
                 start // spec.block_size,
                 wire=wire, tp_p=tp_p, tp_d=tp_d, count=count,
-                front=start % spec.block_size, rmw=rmw, kernel=kernel)
+                front=start % spec.block_size, rmw=rmw, kernel=kernel,
+                interpret=interpret)
             caches[gi][pi] = dict(pools, k_pool=k_pool, v_pool=v_pool)
 
         d_engine.caches = tuple(tuple(g) for g in caches)
@@ -353,14 +375,15 @@ class DisaggPipeline:
     @staticmethod
     def _write_pages_vec(spec: PC.KVPageSpec, pool: jax.Array, block_ids,
                          canon: jax.Array, start: int, *, rmw: bool = False,
-                         kernel: bool = False) -> jax.Array:
+                         kernel: bool = False,
+                         interpret: bool = False) -> jax.Array:
         """Jit-compiled single-pass re-page (see
         :func:`_repage_pool_body`); one compiled program per
         (spec, chunk shape, in-page offset)."""
         return _repage_pool(spec, pool, jnp.asarray(block_ids, jnp.int32),
                             jnp.asarray(canon), start // spec.block_size,
                             front=start % spec.block_size, rmw=rmw,
-                            kernel=kernel)
+                            kernel=kernel, interpret=interpret)
 
     @staticmethod
     def _write_pages(spec: PC.KVPageSpec, pool: jax.Array, block_ids,

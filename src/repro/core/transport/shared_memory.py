@@ -34,7 +34,9 @@ outstanding segments from the parent.
 from __future__ import annotations
 
 import dataclasses
+import os
 import pickle
+import secrets
 import weakref
 from multiprocessing import shared_memory
 from typing import Any, Dict, Set, Tuple
@@ -66,10 +68,14 @@ class SharedMemoryConnector(KVConnector):
 
     def __init__(self, bandwidth_gbps: float = 25.0,
                  buffer_capacity_bytes: int = 1 << 32,
-                 max_inflight: int = 32):
+                 max_inflight: int = 32, owner_pid: int = 0):
         super().__init__(bandwidth_gbps=bandwidth_gbps,
                          buffer_capacity_bytes=buffer_capacity_bytes,
                          fixed_latency_s=0.0, max_inflight=max_inflight)
+        # segments are named psm_<owner pid>_<random>: the owner is this
+        # process, or the cluster parent for a worker's connector, so the
+        # segments of one process tree stand apart from any other's
+        self._name_prefix = f"psm_{owner_pid or os.getpid():x}_"
         self._segments: Dict[str, shared_memory.SharedMemory] = {}
         self._adopted: Set[str] = set()
         # segments whose close() hit BufferError (a reader's view was still
@@ -139,7 +145,13 @@ class SharedMemoryConnector(KVConnector):
     def _new_segment(self, nbytes: int) -> shared_memory.SharedMemory:
         self.pool.acquire(nbytes)
         try:
-            return shared_memory.SharedMemory(create=True, size=nbytes)
+            while True:
+                try:
+                    return shared_memory.SharedMemory(
+                        name=self._name_prefix + secrets.token_hex(4),
+                        create=True, size=nbytes)
+                except FileExistsError:
+                    continue              # name taken: draw another
         except Exception:
             self.pool.release(nbytes)
             raise

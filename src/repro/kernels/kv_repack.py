@@ -85,7 +85,7 @@ def scatter_pages(spec: KVPageSpec, pool: jax.Array, block_ids: jax.Array,
             pl.BlockSpec((1,) + (spec.block_size, spec.kv_heads,
                                  spec.head_dim),
                          lambda i, ids: (i, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),     # aliased full pool
+            pl.BlockSpec(memory_space=pl.ANY),     # aliased full pool
         ],
         out_specs=pl.BlockSpec((1,) + spec.page_shape(),
                                lambda i, ids: (ids[i], 0, 0, 0)),
@@ -100,8 +100,8 @@ def scatter_pages(spec: KVPageSpec, pool: jax.Array, block_ids: jax.Array,
 
 def _scatter_overlay_kernel(block_ids, canon_ref, cur_ref, pool_in_ref,
                             pool_out_ref, *, layout: str, front: int,
-                            seq_len: int, block_size: int):
-    i = pl.program_id(0)
+                            seq_len: int, block_size: int, span: int):
+    i = pl.program_id(0) % span                          # page within span
     canon = canon_ref[0]                                 # (bs, kv, hd)
     cur = jnp.transpose(cur_ref[0], _to_canon_perm(layout))
     row = jax.lax.broadcasted_iota(jnp.int32, canon.shape, 0)
@@ -115,9 +115,14 @@ def _scatter_overlay_kernel(block_ids, canon_ref, cur_ref, pool_in_ref,
 def scatter_pages_overlay(spec: KVPageSpec, pool: jax.Array,
                           block_ids: jax.Array, canon: jax.Array,
                           front: int, seq_len: int,
-                          interpret: bool = False) -> jax.Array:
+                          interpret: bool = False, span: int = 0
+                          ) -> jax.Array:
     """Scatter canonical pages into ``pool`` while preserving rows outside
     ``[front, front + seq_len)`` of the flattened page span.
+
+    ``span`` (default: every page) is the number of pages of one span:
+    several spans — one per layer, stacked into one pool by the caller —
+    go through one call, each merged at the same ``front``/``seq_len``.
 
     ``canon``: (nb, bs, kv, hd) pages whose flat rows ``front .. front +
     seq_len`` hold the incoming stream (outside that range the content is
@@ -129,7 +134,7 @@ def scatter_pages_overlay(spec: KVPageSpec, pool: jax.Array,
     nb = block_ids.shape[0]
     kernel = functools.partial(
         _scatter_overlay_kernel, layout=spec.layout, front=front,
-        seq_len=seq_len, block_size=spec.block_size)
+        seq_len=seq_len, block_size=spec.block_size, span=span or nb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nb,),
@@ -138,7 +143,7 @@ def scatter_pages_overlay(spec: KVPageSpec, pool: jax.Array,
                          lambda i, ids: (i, 0, 0, 0)),
             pl.BlockSpec((1,) + spec.page_shape(),       # current dst page
                          lambda i, ids: (ids[i], 0, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),        # aliased full pool
+            pl.BlockSpec(memory_space=pl.ANY),        # aliased full pool
         ],
         out_specs=pl.BlockSpec((1,) + spec.page_shape(),
                                lambda i, ids: (ids[i], 0, 0, 0)),

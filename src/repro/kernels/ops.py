@@ -1,15 +1,13 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On TPU the kernels run compiled; elsewhere (this CPU container) they run in
-``interpret=True`` mode, which executes the kernel body op-by-op — the
-correctness path the test sweeps exercise. ``force_interpret`` pins the
-mode for tests.
+The kernels run compiled on the TPU backend. ``force_interpret=True`` runs
+the kernel body op-by-op in interpret mode instead — the correctness path
+the CPU test sweeps take. Interpret mode is never chosen silently: a
+compiled kernel asked for on any other backend is an error.
 """
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
@@ -19,17 +17,23 @@ from repro.kernels import paged_attention as _pa
 from repro.serving.paged_cache import KVPageSpec
 
 
-def _interpret(force: Optional[bool]) -> bool:
-    if force is not None:
-        return force
-    return jax.default_backend() != "tpu"
+def _interpret(force: bool) -> bool:
+    if force:
+        return True
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(
+            f"Pallas kernels compile for the TPU backend only (JAX backend "
+            f"is {backend!r}); pass force_interpret=True to run them in "
+            f"interpret mode")
+    return False
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
                                    "force_interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    force_interpret: Optional[bool] = None):
+                    force_interpret: bool = False):
     """Causal flash attention. q: (B,H,Sq,d); k,v: (B,KV,Skv,d)."""
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                block_q=block_q, block_k=block_k,
@@ -39,7 +43,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 @partial(jax.jit, static_argnames=("window", "force_interpret"))
 def paged_attention(q, k_pool, v_pool, block_table, seq_lens, *,
                     window: int = 0,
-                    force_interpret: Optional[bool] = None):
+                    force_interpret: bool = False):
     """Decode attention over paged pools. q: (B,H,d); pools (N,bs,KV,d)."""
     return _pa.paged_attention(q, k_pool, v_pool, block_table, seq_lens,
                                window=window,
@@ -48,35 +52,37 @@ def paged_attention(q, k_pool, v_pool, block_table, seq_lens, *,
 
 @partial(jax.jit, static_argnames=("spec", "force_interpret"))
 def gather_pages(spec: KVPageSpec, pool, block_ids, *,
-                 force_interpret: Optional[bool] = None):
+                 force_interpret: bool = False):
     return _kr.gather_pages(spec, pool, block_ids,
                             interpret=_interpret(force_interpret))
 
 
 @partial(jax.jit, static_argnames=("spec", "force_interpret"))
 def scatter_pages(spec: KVPageSpec, pool, block_ids, canon, *,
-                  force_interpret: Optional[bool] = None):
+                  force_interpret: bool = False):
     return _kr.scatter_pages(spec, pool, block_ids, canon,
                              interpret=_interpret(force_interpret))
 
 
-@partial(jax.jit, static_argnames=("spec", "front", "seq_len",
+@partial(jax.jit, static_argnames=("spec", "front", "seq_len", "span",
                                    "force_interpret"))
 def scatter_pages_overlay(spec: KVPageSpec, pool, block_ids, canon, *,
-                          front: int, seq_len: int,
-                          force_interpret: Optional[bool] = None):
-    """Scatter preserving rows outside [front, front+seq_len) (streamed
-    chunk re-page: partial head/tail blocks merge inside the kernel)."""
+                          front: int, seq_len: int, span: int = 0,
+                          force_interpret: bool = False):
+    """Scatter preserving rows outside [front, front+seq_len) of each
+    ``span``-page run (streamed chunk re-page: partial head/tail blocks
+    merge inside the kernel)."""
     return _kr.scatter_pages_overlay(spec, pool, block_ids, canon, front,
                                      seq_len,
-                                     interpret=_interpret(force_interpret))
+                                     interpret=_interpret(force_interpret),
+                                     span=span)
 
 
 @partial(jax.jit, static_argnames=("src", "dst", "seq_len",
                                    "force_interpret"))
 def repack(src: KVPageSpec, dst: KVPageSpec, src_pool, src_blocks,
            dst_pool, dst_blocks, seq_len: int, *,
-           force_interpret: Optional[bool] = None):
+           force_interpret: bool = False):
     """Vendor alignment: P pool → canonical 1-D → D pool (paper Fig. 3)."""
     return _kr.repack(src, dst, src_pool, src_blocks, dst_pool, dst_blocks,
                       seq_len, interpret=_interpret(force_interpret))

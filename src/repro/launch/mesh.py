@@ -15,17 +15,21 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh (hillclimb variants: e.g. (8, 32), (4, 64))."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (hillclimb variants: e.g. (8, 32), (4, 64)). Axes
+    are Auto: the model's ``with_sharding_constraint`` anchors and the
+    launch layer's ``NamedSharding`` rules are written for Auto axes, and
+    ``jax.make_mesh`` defaults to Explicit ones."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> Tuple[str, ...]:

@@ -10,17 +10,19 @@ model with real numerics.
   python -m repro.launch.serve --arch qwen3-4b --shape decode_32k
   python -m repro.launch.serve --demo
 """
-import os
-if "XLA_FLAGS" not in os.environ:      # 512 fake chips unless launched real
-    os.environ["XLA_FLAGS"] = \
-        "--xla_force_host_platform_device_count=512 " \
-        "--xla_disable_hlo_passes=while-loop-invariant-code-motion," \
-        "while-loop-expensive-invariant-code-motion"
-
 import argparse
+import os
 
 
 def compile_programs(arch: str, shape: str, multi_pod: bool) -> None:
+    # dry run only: the production mesh lowers onto 512 placeholder host
+    # devices (set before JAX is first imported; see dryrun.py for the two
+    # disabled passes). The served path compiles with default passes.
+    os.environ.setdefault(
+        "XLA_FLAGS",
+        "--xla_force_host_platform_device_count=512 "
+        "--xla_disable_hlo_passes=while-loop-invariant-code-motion,"
+        "while-loop-expensive-invariant-code-motion")
     from repro.launch.cells import get_cell
     from repro.launch.mesh import make_production_mesh
     from repro.launch.steps import (make_prefill_artifacts,

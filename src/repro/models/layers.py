@@ -718,8 +718,6 @@ def _moe_mlp_shard_map(p: Params, cfg: ModelConfig, x: jax.Array,
                        dctx) -> jax.Array:
     from jax.sharding import PartitionSpec as P
 
-    from repro.kernels._jax_compat import shard_map
-
     M = dctx.model_axis
     dp = dctx.dp_axes if x.shape[0] % _axes_size(dctx.mesh, dctx.dp_axes) == 0 \
         else ()
@@ -735,8 +733,8 @@ def _moe_mlp_shard_map(p: Params, cfg: ModelConfig, x: jax.Array,
         return _moe_mlp_capacity(pl, cfg, xl, psum_axis=M,
                                  capacity_factor=dctx.moe_capacity_factor)
 
-    return shard_map(body, mesh=dctx.mesh, in_specs=(wspec, xspec),
-                     out_specs=xspec, check_vma=False)(
+    return jax.shard_map(body, mesh=dctx.mesh, in_specs=(wspec, xspec),
+                         out_specs=xspec, check_vma=False)(
         {k: p[k] for k in wspec}, x)
 
 

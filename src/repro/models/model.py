@@ -111,6 +111,14 @@ def _init_group(rng, cfg: ModelConfig, g: Group) -> Tuple[Params, ...]:
 
 
 def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
+    """Random parameters from ``rng``. Built as one jitted program, so each
+    leaf is produced directly in ``param_dtype`` (run eagerly, the largest
+    stacked leaf would first be drawn whole in float32, then cast)."""
+    return _init_params(rng, cfg)
+
+
+@partial(jax.jit, static_argnums=1)
+def _init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     ks = jax.random.split(rng, 8)
     d, v = cfg.d_model, cfg.vocab_size
     p: Params = {

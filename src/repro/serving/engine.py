@@ -5,9 +5,8 @@ instance, or an integrated instance). Vendor-specific VRAM management is the
 engine's ``KVPageSpec`` (block size / layout / dtype); compute dtype and the
 logical TP degree used for KV sharding complete the vendor profile.
 
-The engine is device-agnostic: on this CPU container it runs the tiny-model
-functional path; on a TPU mesh the same jitted callables are pjit'd by the
-launcher.
+The engine runs on whatever single device JAX gives its process: the TPU
+chip on an accelerator host, the CPU backend in tests.
 """
 from __future__ import annotations
 
@@ -363,8 +362,8 @@ class PrefillStream:
         eng.stats.prefill_tokens += c1 - c0
         eng.stats.prefill_chunks += 1
         if c1 == self.seq_len:
-            self.first_token = int(
-                eng._sample(np.asarray(logits[:, -1]), req)[0])
+            self.first_token = eng._sample_first(np.asarray(logits[:, -1]),
+                                                 req)
             self._tail = self._extract_tail()
         dt = time.perf_counter() - t0
         eng._note_prefill_compute(dt)
@@ -588,8 +587,10 @@ class Engine:
         self.allocator = BlockAllocator(num_blocks)
         self.allocator.allocate("__scratch__", 1)   # trash page for idle slots
         self._scratch_block = self.allocator.blocks_of("__scratch__")[0]
-        self.caches = M.init_paged_caches(cfg, self.specs, num_blocks,
-                                          batch=max_batch, mem_len=self.mem_len)
+        # a prefill-only instance never decodes: it holds no paged pool
+        # (its KV leaves through the wire, the D side owns the pages)
+        self.caches = None if role == "prefill" else M.init_paged_caches(
+            cfg, self.specs, num_blocks, batch=max_batch, mem_len=self.mem_len)
         # slot bookkeeping (host side)
         self.slot_req: List[Optional[Request]] = [None] * max_batch
         # a slot is reserved when slot_req is set; ready once its KV has
@@ -627,7 +628,9 @@ class Engine:
                                    cfg.cdtype, mem_len=self.mem_len)
             return M.prefill(params, cfg, inputs, caches)
 
-        @jax.jit
+        # the pools are donated: each step updates them in place instead of
+        # writing a second copy of the whole pool
+        @partial(jax.jit, donate_argnames=("caches",))
         def _decode(params, tokens, seq_lens, block_table, write_blocks,
                     write_slots, caches):
             return M.decode_step_paged(params, cfg, tokens, seq_lens,
@@ -719,9 +722,9 @@ class Engine:
             inputs["patches"] = jnp.asarray(req.patches)[None]
         plen = req.prompt_len + (req.patches.shape[0] if req.patches is not None else 0)
         last_logits, caches = self._prefill_fn(self.params, inputs, plen)
-        first_token = self._sample(np.asarray(last_logits), req)[0]
+        first_token = self._sample_first(np.asarray(last_logits), req)
         package = self._package_handoff(caches, plen)
-        package["first_token"] = int(first_token)
+        package["first_token"] = first_token
         package["seq_len"] = plen
         self.stats.prefill_tokens += plen
         self.stats.prefill_chunks += 1
@@ -1001,6 +1004,12 @@ class Engine:
         return out
 
     # ------------------------------------------------------------------ #
+    def _sample_first(self, logits: np.ndarray, req: Request) -> int:
+        """Sample the first token from the last prompt position's logits
+        (1, V), keeping them on the request as served."""
+        req.first_logits = np.asarray(logits[0], np.float32)
+        return int(self._sample(logits, req)[0])
+
     def _sample(self, logits: np.ndarray, req: Request) -> np.ndarray:
         if req.temperature <= 0.0:
             return np.argmax(logits, axis=-1).astype(np.int32)

@@ -27,6 +27,7 @@ import queue
 import time
 from typing import Any, Deque, Dict, Optional, Tuple
 
+from repro.serving.multiproc.chips import describe_device
 from repro.serving.multiproc.messages import (AbortStream, BeginStream,
                                               ChunkReady, ChunkRepaged,
                                               FinalizeStream, Heartbeat,
@@ -52,8 +53,8 @@ class DWorker:
     """Event loop state of one decode worker."""
 
     def __init__(self, spec: WorkerSpec, cmd_q, evt_q):
-        from repro.serving.multiproc.jit_cache import enable_jit_cache
-        enable_jit_cache(spec.jit_cache_dir)  # before any jit touches XLA
+        from repro.serving.jit_cache import enable_jit_cache
+        enable_jit_cache()                    # before any jit touches XLA
 
         import jax
 
@@ -280,7 +281,7 @@ class DWorker:
     # -- main loop ----------------------------------------------------------- #
     def run(self) -> None:
         self.evt_q.put(Hello(self.iid, os.getpid(), self.engine.name,
-                             role="D"))
+                             role="D", device=describe_device()))
         last_beat = time.monotonic()
         while not self.stop:
             progressed = self._drain_cmds()

@@ -50,13 +50,12 @@ import dataclasses
 import multiprocessing as mp
 import os
 import queue
-import tempfile
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
 from repro.core.transport.base import TransferStats
 from repro.serving import router
-from repro.serving.multiproc import d_worker, p_worker
+from repro.serving.multiproc import chips, d_worker, p_worker
 from repro.serving.multiproc.messages import (AbortStream, BeginStream,
                                               ChunkReady, ChunkRepaged,
                                               ChunkStaged, ClusterSpec,
@@ -72,18 +71,6 @@ from repro.serving.request import Request, State
 from repro.serving.router import (AdmissionConfig, should_admit,
                                   update_ttft_ema)
 from repro.serving.scheduler import RuntimeStats, requeue_for_retry
-
-
-def default_jit_cache_dir() -> Optional[str]:
-    """Shared persistent XLA compilation-cache directory for every worker
-    process of this host. N workers (and repeat runs) compile each program
-    once instead of N times — on small hosts redundant per-process jit
-    compilation, not compute, dominated multi-instance wall time.
-    Overridable via ``REPRO_JIT_CACHE_DIR`` (empty string disables)."""
-    env = os.environ.get("REPRO_JIT_CACHE_DIR")
-    if env is not None:
-        return env or None
-    return os.path.join(tempfile.gettempdir(), "repro-jax-cache")
 
 
 def _unlink_segment(name: str) -> None:
@@ -127,6 +114,7 @@ class _Instance:
     cmd_q: Optional[Any] = None
     gen: int = 0                          # spawn generation (respawns bump)
     pid: Optional[int] = None
+    chip: Optional[int] = None            # accelerator chip handed to it
     hello: bool = False                   # worker reported ready (routable)
     last_seen: float = 0.0
     draining: bool = False                # no new work routed here
@@ -201,7 +189,6 @@ class ClusterRuntime:
                  stall_timeout_s: float = 120.0,
                  max_respawns: int = 4,
                  admission: Optional[AdmissionConfig] = None,
-                 jit_cache_dir: Optional[str] = "auto",
                  fault_exit_after_chunks: Optional[int] = None,
                  fault_exit_after_tokens: Optional[int] = None):
         from repro.core.compat.precision import WireFormat
@@ -209,7 +196,8 @@ class ClusterRuntime:
         self._prefix = any(e.prefix_cache for e in cluster.p + cluster.d)
         self._wire = wire or WireFormat("raw", "float32")
         self._codec = codec
-        self._ck = dict(connector_kwargs or {})
+        # worker connectors name their segments after this parent
+        self._ck = dict(connector_kwargs or {}, owner_pid=os.getpid())
         self._prefill_chunk = prefill_chunk
         # validated here so a typo fails at construction, not in a worker
         self._prefill_mode = PrefillMode(prefill_mode).value
@@ -219,12 +207,15 @@ class ClusterRuntime:
         self.admission = admission
         # measured TTFT EMA (arrival → first token), the admission signal
         self.ttft_ema: Optional[float] = None
-        self._jit_cache_dir = default_jit_cache_dir() \
-            if jit_cache_dir == "auto" else jit_cache_dir
+        # TPU chips on this host, counted at start() (0: workers run on
+        # the CPU and need no chip)
+        self._chips = 0
         self.stats = RuntimeStats()
         self.transfer_stats = TransferStats()     # parent-measured + merged
         self.worker_stats: Dict[str, Dict[str, float]] = {}
         self.worker_pids: Dict[str, int] = {}
+        # iid → the device the worker computes on, from its Hello
+        self.worker_devices: Dict[str, Dict[str, Any]] = {}
         self.stream_failures: List[Tuple[str, str]] = []
         self.crashes: Dict[str, int] = {"P": 0, "D": 0}
         self.respawns: Dict[str, int] = {"P": 0, "D": 0}
@@ -259,7 +250,6 @@ class ClusterRuntime:
                           prefill_chunk=self._prefill_chunk,
                           prefill_mode=self._prefill_mode,
                           instance_id=iid,
-                          jit_cache_dir=self._jit_cache_dir,
                           fault_exit_after_chunks=fault_exit_after_chunks,
                           fault_exit_after_tokens=fault_exit_after_tokens)
         self._instances[iid] = _Instance(
@@ -269,6 +259,16 @@ class ClusterRuntime:
 
     # -- process lifecycle ------------------------------------------------- #
     def start(self, spawn_timeout_s: float = 120.0) -> None:
+        # one process per chip: the parent must not hold the accelerator,
+        # and a topology wider than the host fails here, not in a worker
+        # that would hang opening a chip another worker holds
+        chips.check_parent_off_chip()
+        self._chips = chips.tpu_chip_count()
+        if self._chips and len(self._instances) > self._chips:
+            raise RuntimeError(
+                f"{self.cluster.ratio()} needs {len(self._instances)} worker "
+                f"processes, one chip each, but this host has "
+                f"{self._chips} TPU chip(s)")
         self._evt_q = self._ctx.Queue()
         for inst in self._instances.values():
             self._spawn(inst)
@@ -282,15 +282,29 @@ class ClusterRuntime:
             inst.spec = dataclasses.replace(inst.spec,
                                             fault_exit_after_chunks=None,
                                             fault_exit_after_tokens=None)
+        env: Dict[str, str] = {}
+        if self._chips:
+            if inst.chip is None:             # a respawn keeps its chip
+                inst.chip = self._free_chip()
+            env = chips.worker_env(inst.chip)
         inst.cmd_q = self._ctx.Queue()
         target = p_worker.p_main if inst.role == "P" else d_worker.d_main
         proc = self._ctx.Process(target=target,
                                  args=(inst.spec, inst.cmd_q, self._evt_q),
                                  daemon=True,
                                  name=f"repro-{inst.iid.lower()}")
-        proc.start()
+        with chips.environ(env):              # the child copies it at spawn
+            proc.start()
         inst.proc = proc
         inst.last_seen = time.monotonic()
+
+    def _free_chip(self) -> int:
+        used = {i.chip for i in self._instances.values()}
+        free = [c for c in range(self._chips) if c not in used]
+        if not free:
+            raise RuntimeError(f"all {self._chips} TPU chip(s) of this host "
+                               f"already run a worker")
+        return free[0]
 
     def _await_hello(self, iids: set, timeout_s: float) -> None:
         deadline = time.monotonic() + timeout_s
@@ -323,6 +337,8 @@ class ClusterRuntime:
         builds its engine)."""
         if role not in ("P", "D"):
             raise ValueError(f"role must be 'P' or 'D', got {role!r}")
+        if self._evt_q is not None and self._chips:
+            self._free_chip()                 # fail fast: no chip left
         iid = self._add_member(espec, role)
         if self._evt_q is not None:
             self._spawn(self._instances[iid])
@@ -536,6 +552,8 @@ class ClusterRuntime:
                 inst.pid = msg.pid
                 inst.hello = True
             self.worker_pids[msg.src] = msg.pid
+            if msg.device is not None:
+                self.worker_devices[msg.src] = dict(msg.device)
             return
         if isinstance(msg, Heartbeat):
             if inst is not None:

@@ -114,10 +114,6 @@ class WorkerSpec:
     # as a string so the spec stays picklable without an engine import
     prefill_mode: str = "auto"
     heartbeat_s: float = 0.5
-    # persistent XLA compilation-cache dir shared by every worker process
-    # on this host (None disables): N workers compile each jit program
-    # once, not N times — see launcher.default_jit_cache_dir
-    jit_cache_dir: Optional[str] = None
     # instance id on the control plane (defaults to the engine name; the
     # launcher keeps them unique across the pool)
     instance_id: str = ""
@@ -210,6 +206,8 @@ class Hello:
     pid: int
     engine_name: str
     role: str = ""                        # "P" | "D"
+    # the device this worker computes on (chips.describe_device)
+    device: Optional[Dict[str, Any]] = None
 
 
 @dataclasses.dataclass(frozen=True)

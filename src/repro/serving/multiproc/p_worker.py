@@ -22,6 +22,7 @@ import queue
 import time
 from typing import Any, Deque
 
+from repro.serving.multiproc.chips import describe_device
 from repro.serving.multiproc.messages import (ChunkStaged, Heartbeat, Hello,
                                               PrefillDone, PrefillFailed,
                                               ReleaseStaged, Shutdown,
@@ -42,8 +43,8 @@ class PWorker:
     """Event loop state of one prefill worker."""
 
     def __init__(self, spec: WorkerSpec, cmd_q, evt_q):
-        from repro.serving.multiproc.jit_cache import enable_jit_cache
-        enable_jit_cache(spec.jit_cache_dir)  # before any jit touches XLA
+        from repro.serving.jit_cache import enable_jit_cache
+        enable_jit_cache()                    # before any jit touches XLA
 
         from repro.core.disagg import DisaggPipeline
         from repro.core.transport import SharedMemoryConnector
@@ -211,7 +212,7 @@ class PWorker:
     # -- main loop ---------------------------------------------------------- #
     def run(self) -> None:
         self.evt_q.put(Hello(self.iid, os.getpid(), self.engine.name,
-                             role="P"))
+                             role="P", device=describe_device()))
         try:
             while not self.stop:
                 if self.backlog:

@@ -46,6 +46,9 @@ class Request:
     retries: int = 0
     decode_steps_at_dispatch: int = 0
     chunks_streamed: int = 0                # KV chunks shipped P→D
+    # logits (V,) float32 at the last prompt position, as served by the P
+    # instance that sampled the first token (kept for correctness checks)
+    first_logits: Optional[np.ndarray] = None
 
     @property
     def prompt_len(self) -> int:

@@ -19,6 +19,10 @@ The acceptance bar for genuine disaggregation:
      (the ``weakref.finalize`` guard).
   4. *planner round trip*: ``plan_deployment``'s chosen instance counts
      launch unmodified through ``DeploymentPlan.to_cluster_spec``.
+  5. *one chip per worker*: the parent stays off the accelerator, every
+     worker is handed its own chip through its environment, and a
+     topology wider than the host fails before anything is spawned (the
+     chip count is stubbed here; the workers still compute on the CPU).
 """
 import gc
 import os
@@ -36,7 +40,7 @@ from repro.models import model as M
 from repro.serving import router
 from repro.serving.engine import Engine, VendorProfile
 from repro.serving.multiproc import (ClusterRuntime, ClusterSpec, EngineSpec,
-                                     TwoProcessRuntime, serve_cluster,
+                                     TwoProcessRuntime, chips, serve_cluster,
                                      serve_two_process)
 from repro.serving.multiproc.launcher import _interval_overlap, _union
 from repro.serving.request import Request
@@ -94,11 +98,13 @@ def _serve_single(reqs):
 
 
 def _shm_files():
-    """Named shared-memory data segments (``psm_*`` is CPython's
-    ``SharedMemory`` name prefix — queue semaphores etc. don't count)."""
+    """Named shared-memory data segments this test process owns: its own
+    connectors' and its cluster workers' (``psm_<this pid>_*``). Segments
+    of tests running concurrently in other processes don't count."""
     if not os.path.isdir("/dev/shm"):
         return None
-    return {f for f in os.listdir("/dev/shm") if f.startswith("psm_")}
+    mine = f"psm_{os.getpid():x}_"
+    return {f for f in os.listdir("/dev/shm") if f.startswith(mine)}
 
 
 # --------------------------------------------------------------------- #
@@ -440,3 +446,52 @@ def test_union_merges_overlapping_and_drops_empty():
     wire = _union([(0.0, 2.0), (1.0, 3.0)])
     assert sum(_interval_overlap(w, [(0.0, 10.0)]) for w in wire) \
         == pytest.approx(3.0)
+
+
+# --------------------------------------------------------------------- #
+# 5. one chip per worker
+# --------------------------------------------------------------------- #
+def test_topology_wider_than_host_fails_fast(monkeypatch):
+    monkeypatch.setattr(chips, "tpu_chip_count", lambda: 2)
+    rt = ClusterRuntime(_cluster(2, 2))
+    with pytest.raises(RuntimeError, match="4 worker processes, one chip "
+                                           "each, but this host has 2"):
+        rt.start()
+    assert all(i.proc is None for i in rt._instances.values())
+
+
+def test_parent_holding_the_accelerator_is_refused(monkeypatch):
+    jax.devices()                                  # backend initialized
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rt = ClusterRuntime(_cluster(1, 1))
+    with pytest.raises(RuntimeError, match="holds its devices"):
+        rt.start()
+
+
+def test_worker_env_confines_one_chip():
+    env = chips.worker_env(3)
+    assert env["TPU_VISIBLE_CHIPS"] == "3"
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_PROCESS_ADDRESSES"] == \
+        f"localhost:{env['TPU_PROCESS_PORT']}"
+
+
+def test_cluster_workers_hold_one_chip_each(monkeypatch):
+    """chip_smoke.py's four-chip path at a tiny size: 2P×2D with four
+    (stubbed) chips, each worker reports its own chip and one device, and
+    serves what the single-process path serves."""
+    import chip_smoke as S
+    from test_chip_smoke import TINY
+    monkeypatch.setattr(chips, "tpu_chip_count", lambda: 4)
+    lengths, max_new, chunk = (8, 16, 24, 40), 3, 16
+    reqs = S.build_requests(TINY, lengths, max_new, seed=0)
+    tokens, rt = S.serve_cluster(TINY, reqs, lengths, max_new, seed=0,
+                                 prefill_chunk=chunk, timeout_s=300.0)
+    ref = S.serve_single(TINY, lengths, max_new, seed=0,
+                         prefill_chunk=chunk)["tokens"]
+    devs = rt.worker_devices
+    assert sorted(devs) == ["D0", "D1", "P0", "P1"]
+    assert sorted(v["chip"] for v in devs.values()) == ["0", "1", "2", "3"]
+    assert all(v["count"] == 1 for v in devs.values())
+    assert tokens == ref

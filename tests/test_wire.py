@@ -169,7 +169,8 @@ def _stream_pools(cfg, params, vd, wire, codec, backend="inproc",
     p, d = _pd_pair(cfg, params, vd)
     conn = make_connector(backend)
     pipe = DisaggPipeline(conn, wire, codec=codec,
-                          repage_kernel=repage_kernel)
+                          repage_kernel=repage_kernel,
+                          kernel_interpret=repage_kernel)
     pipe.handoff_streamed(_req(cfg, plen=13), p, d, chunk_tokens=chunk_tokens,
                           chunked_compute=False)
     assert conn.pool.in_use == 0
@@ -257,7 +258,8 @@ def test_overlay_chunk_sequence_never_clobbers(layout, bs, chunk):
         for st in range(0, S, chunk):
             cn = stream[:, st:st + chunk]
             cur = DisaggPipeline._write_pages_vec(spec, cur, ids, cn, st,
-                                                  rmw=True, kernel=kernel)
+                                                  rmw=True, kernel=kernel,
+                                                  interpret=kernel)
             got = jax.vmap(lambda pl: gather_sequence(spec, pl, ids,
                                                       min(st + chunk, S))
                            )(cur)
@@ -292,7 +294,8 @@ def test_write_pages_vec_matches_legacy_write_pages(start):
     vec = DisaggPipeline._write_pages_vec(spec, pool, ids, canon, start,
                                           rmw=True)
     ker = DisaggPipeline._write_pages_vec(spec, pool, ids, canon, start,
-                                          rmw=True, kernel=True)
+                                          rmw=True, kernel=True,
+                                          interpret=True)
     assert bool(jnp.array_equal(legacy, vec))
     assert bool(jnp.array_equal(legacy, ker))
 
