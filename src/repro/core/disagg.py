@@ -51,6 +51,7 @@ from repro.serving import paged_cache as PC
 from repro.serving.engine import (Engine, kv_entries_with_start,
                                   slice_kv_entries)
 from repro.serving.request import Request
+from repro.serving.tracing import span
 
 
 def _to_device(payload):
@@ -578,12 +579,16 @@ class StreamedHandoff:
                 break                  # channel held by other flights —
         #                                issue_read below surfaces the limit
         tr = self.pipeline.transfer
-        wire_chunk = self.pipeline.encode_chunk(self.p_engine, chunk)
+        rid = self.req.req_id
+        with span("pd.handoff.encode", req=rid):
+            wire_chunk = self.pipeline.encode_chunk(self.p_engine, chunk)
         key = f"{self.req.req_id}@{self.p_engine.name}" \
               f"#t{self.req.retries}c{self.chunks_sent}"
         if self._t_first_stage is None:
             self._t_first_stage = time.monotonic()
-        nbytes = tr.stage(key, wire_chunk, self.meta)
+        with span("pd.handoff.stage", req=rid,
+                  bytes=getattr(wire_chunk, "nbytes", 0)):
+            nbytes = tr.stage(key, wire_chunk, self.meta)
         try:
             handle = tr.issue_read(key)
         except Exception:
@@ -608,9 +613,15 @@ class StreamedHandoff:
         if self.d_engine.failed:
             raise RuntimeError(f"instance {self.d_engine.name} is down")
         tr = self.pipeline.transfer
-        payload, meta = handle.wait()
-        self.pipeline.materialize(self.d_engine, self.slot, self.block_ids,
-                                  _to_device(payload), meta, rmw=True)
+        rid = self.req.req_id
+        with span("pd.handoff.read", req=rid):
+            payload, meta = handle.wait()
+        with span("pd.handoff.to_device", req=rid):
+            dev_payload = _to_device(payload)
+        with span("pd.handoff.repage", req=rid):
+            self.pipeline.materialize(self.d_engine, self.slot,
+                                      self.block_ids, dev_payload, meta,
+                                      rmw=True)
         if hasattr(payload, "release"):
             payload.release()      # drop zero-copy views before the segment
             #                        backing this chunk is closed

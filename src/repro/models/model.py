@@ -203,7 +203,8 @@ def _window(cfg: ModelConfig) -> int:
 
 
 def _mlp_apply(p: Params, cfg: ModelConfig, moe: bool, x: jax.Array) -> jax.Array:
-    return L.moe_mlp(p, cfg, x) if moe else L.swiglu_mlp(p, x)
+    with jax.named_scope("mlp"):
+        return L.moe_mlp(p, cfg, x) if moe else L.swiglu_mlp(p, x)
 
 
 def _apply_layer_full(p, cfg: ModelConfig, g: Group, kind: str, x,
@@ -229,17 +230,20 @@ def _apply_layer_full(p, cfg: ModelConfig, g: Group, kind: str, x,
     h = L.rms_norm(p["norm1"], x, cfg.norm_eps)
     self_cache = cache["self"] if (build and g.cross) else cache
     new_cache: Any = None
-    if cfg.attention_kind == "mla":
-        out, (ckv, kpe) = L.mla_block(p["attn"], cfg, h, positions, lengths)
-        if build:
-            new_cache = L.mla_cache_from_prefill(self_cache, ckv, kpe,
-                                                 positions)
-    else:
-        out, (k, v) = L.attention_block(p["attn"], cfg, h, positions,
-                                        causal=g.causal, lengths=lengths,
-                                        window=win)
-        if build:
-            new_cache = L.kv_cache_from_prefill(self_cache, k, v, positions)
+    with jax.named_scope("attention"):
+        if cfg.attention_kind == "mla":
+            out, (ckv, kpe) = L.mla_block(p["attn"], cfg, h, positions,
+                                          lengths)
+            if build:
+                new_cache = L.mla_cache_from_prefill(self_cache, ckv, kpe,
+                                                     positions)
+        else:
+            out, (k, v) = L.attention_block(p["attn"], cfg, h, positions,
+                                            causal=g.causal, lengths=lengths,
+                                            window=win)
+            if build:
+                new_cache = L.kv_cache_from_prefill(self_cache, k, v,
+                                                    positions)
     x = x + out
     if g.cross:
         hx = L.rms_norm(p["norm_x"], x, cfg.norm_eps)
@@ -273,11 +277,13 @@ def _apply_layer_decode(p, cfg: ModelConfig, g: Group, kind: str, x,
     win = _window(cfg)
     h = L.rms_norm(p["norm1"], x, cfg.norm_eps)
     self_cache = cache["self"] if g.cross else cache
-    if cfg.attention_kind == "mla":
-        out, new_self = L.mla_decode(p["attn"], cfg, h, positions, self_cache)
-    else:
-        out, new_self = L.attention_decode(p["attn"], cfg, h, positions,
-                                           self_cache, window=win)
+    with jax.named_scope("attention"):
+        if cfg.attention_kind == "mla":
+            out, new_self = L.mla_decode(p["attn"], cfg, h, positions,
+                                         self_cache)
+        else:
+            out, new_self = L.attention_decode(p["attn"], cfg, h, positions,
+                                               self_cache, window=win)
     x = x + out
     new_cache: Any = new_self
     if g.cross:
@@ -370,11 +376,12 @@ def embed_tokens(params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
 
 
 def lm_logits(params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
-    x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
-    head = params.get("lm_head", None)
-    if head is None:
-        head = params["embed"].T
-    return jnp.einsum("...d,dv->...v", x, head.astype(x.dtype))
+    with jax.named_scope("lm_head"):
+        x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
+        head = params.get("lm_head", None)
+        if head is None:
+            head = params["embed"].T
+        return jnp.einsum("...d,dv->...v", x, head.astype(x.dtype))
 
 
 def _merge_frontend(params, cfg: ModelConfig, inputs: Dict[str, jax.Array]):
@@ -539,14 +546,15 @@ def _apply_layer_decode_paged(p, cfg: ModelConfig, g: Group, kind: str, x,
         return x, st
     win = _window(cfg)
     h = L.rms_norm(p["norm1"], x, cfg.norm_eps)
-    if cfg.attention_kind == "mla":
-        out, new_pools = L.mla_decode_paged(
-            p["attn"], cfg, h, positions, cache, block_table, seq_lens,
-            write_blocks, write_slots, specs["ckv"], specs["kpe"])
-    else:
-        out, new_pools = L.attention_decode_paged(
-            p["attn"], cfg, h, positions, cache, block_table, seq_lens,
-            write_blocks, write_slots, specs["kv"], window=win)
+    with jax.named_scope("attention"):
+        if cfg.attention_kind == "mla":
+            out, new_pools = L.mla_decode_paged(
+                p["attn"], cfg, h, positions, cache, block_table, seq_lens,
+                write_blocks, write_slots, specs["ckv"], specs["kpe"])
+        else:
+            out, new_pools = L.attention_decode_paged(
+                p["attn"], cfg, h, positions, cache, block_table, seq_lens,
+                write_blocks, write_slots, specs["kv"], window=win)
     x = x + out
     new_cache = dict(new_pools)
     if g.cross:

@@ -27,6 +27,7 @@ from repro.models import model as M
 from repro.serving.paged_cache import BlockAllocator, KVPageSpec
 from repro.serving.prefix_cache import HostPrefixStore, PrefixStore, hashing
 from repro.serving.request import Request, State
+from repro.serving.tracing import span
 
 log = logging.getLogger(__name__)
 
@@ -88,7 +89,6 @@ class EngineStats:
     decode_steps: int = 0
     decode_tokens: int = 0
     prefill_seconds: float = 0.0
-    decode_seconds: float = 0.0
     failures_injected: int = 0
     prefix_cached_tokens: int = 0   # prompt tokens replayed from the P-side
     #                                 host prefix store instead of recomputed
@@ -357,13 +357,15 @@ class PrefillStream:
             self._setup_incremental()
         c0 = self._next_start
         c1 = min(c0 + self.chunk_tokens, self.seq_len)
-        logits = self._compute_chunk(c0, c1)
+        with span("pd.prefill.chunk", req=req.req_id, tokens=c1 - c0):
+            logits = self._compute_chunk(c0, c1)
         self._next_start = c1
         eng.stats.prefill_tokens += c1 - c0
         eng.stats.prefill_chunks += 1
         if c1 == self.seq_len:
-            self.first_token = eng._sample_first(np.asarray(logits[:, -1]),
-                                                 req)
+            with span("pd.prefill.first_token", req=req.req_id):
+                self.first_token = eng._sample_first(
+                    np.asarray(logits[:, -1]), req)
             self._tail = self._extract_tail()
         dt = time.perf_counter() - t0
         eng._note_prefill_compute(dt)
@@ -379,7 +381,8 @@ class PrefillStream:
         if c1 <= w0:
             return {"kv": [], "start": c0, "length": 0,
                     "compute_seconds": dt}
-        entries = self._extract_entries(w0, c1)
+        with span("pd.handoff.extract", req=req.req_id):
+            entries = self._extract_entries(w0, c1)
         self._wire_sent = c1
         return {"kv": entries, "start": w0, "length": c1 - w0,
                 "compute_seconds": dt}
@@ -976,31 +979,37 @@ class Engine:
                   if r is not None and self.slot_ready[i]]
         if not active:
             return []
-        t0 = time.perf_counter()
-        write_slots = self.seq_lens % self.block_size
-        write_block_idx = self.seq_lens // self.block_size
-        write_blocks = self.block_tables[np.arange(self.max_batch),
-                                         np.minimum(write_block_idx,
-                                                    self.max_blocks_per_seq - 1)]
-        idle = np.asarray([r is None or not self.slot_ready[i]
-                           for i, r in enumerate(self.slot_req)])
-        write_blocks = np.where(idle, self._scratch_block, write_blocks)
-        logits, self.caches = self._decode_fn(
-            self.params, jnp.asarray(self.last_token[:, None]),
-            jnp.asarray(self.seq_lens), jnp.asarray(self.block_tables),
-            jnp.asarray(write_blocks.astype(np.int32)),
-            jnp.asarray(write_slots.astype(np.int32)), self.caches)
-        logits = np.asarray(logits[:, 0])
-        out = []
-        for slot in active:
-            req = self.slot_req[slot]
-            tok = self._sample(logits[slot:slot + 1], req)[0]
-            self.seq_lens[slot] += 1
-            self.last_token[slot] = tok
-            out.append((slot, req, int(tok)))
+        with span("pd.decode.step", batch=len(active)):
+            with span("pd.decode.prepare"):
+                write_slots = self.seq_lens % self.block_size
+                write_block_idx = self.seq_lens // self.block_size
+                write_blocks = self.block_tables[
+                    np.arange(self.max_batch),
+                    np.minimum(write_block_idx, self.max_blocks_per_seq - 1)]
+                idle = np.asarray([r is None or not self.slot_ready[i]
+                                   for i, r in enumerate(self.slot_req)])
+                write_blocks = np.where(idle, self._scratch_block,
+                                        write_blocks)
+                args = (jnp.asarray(self.last_token[:, None]),
+                        jnp.asarray(self.seq_lens),
+                        jnp.asarray(self.block_tables),
+                        jnp.asarray(write_blocks.astype(np.int32)),
+                        jnp.asarray(write_slots.astype(np.int32)))
+            with span("pd.decode.launch"):
+                logits, self.caches = self._decode_fn(self.params, *args,
+                                                      self.caches)
+            with span("pd.decode.fetch"):
+                logits = np.asarray(logits[:, 0])
+            out = []
+            with span("pd.decode.sample"):
+                for slot in active:
+                    req = self.slot_req[slot]
+                    tok = self._sample(logits[slot:slot + 1], req)[0]
+                    self.seq_lens[slot] += 1
+                    self.last_token[slot] = tok
+                    out.append((slot, req, int(tok)))
         self.stats.decode_steps += 1
         self.stats.decode_tokens += len(active)
-        self.stats.decode_seconds += time.perf_counter() - t0
         return out
 
     # ------------------------------------------------------------------ #

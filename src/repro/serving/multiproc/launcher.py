@@ -497,6 +497,8 @@ class ClusterRuntime:
             p_id = router.pick_p(p_snaps)
             p, d = self._instances[p_id], self._instances[d_id]
             req.state = State.PREFILLING
+            if req.dispatch_time is None:
+                req.dispatch_time = time.monotonic()
             rec = _FlightRecord(req=req, attempt=req.retries,
                                 p_id=p_id, d_id=d_id, p_gen=p.gen,
                                 est_tokens=seq_len, need_blocks=need)

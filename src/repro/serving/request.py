@@ -40,6 +40,9 @@ class Request:
     output_tokens: List[int] = dataclasses.field(default_factory=list)
     prefill_instance: str = ""
     decode_instance: str = ""
+    # first time the request entered PREFILLING (kept across requeues):
+    # queue wait = dispatch − arrival, prefill flight = first token − dispatch
+    dispatch_time: Optional[float] = None
     first_token_time: Optional[float] = None
     last_token_time: Optional[float] = None
     finish_time: Optional[float] = None

@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.serving.engine import Engine, PrefillMode
 from repro.serving.request import Request, State
+from repro.serving.tracing import span
 
 if TYPE_CHECKING:                      # avoid core <-> serving import cycle
     from repro.core.disagg import DisaggPipeline
@@ -118,7 +119,8 @@ class PrefillFlightLoop:
 
     def pump(self, emitted: List[Tuple[Request, int]]) -> None:
         self.prefilled.clear()
-        self._dispatch(emitted)
+        with span("pd.sched.dispatch"):
+            self._dispatch(emitted)
         self._advance_all(emitted)
 
     # -- dispatch --------------------------------------------------------- #
@@ -139,6 +141,8 @@ class PrefillFlightLoop:
                 still_pending.append(req)
                 continue
             req.state = State.PREFILLING
+            if req.dispatch_time is None:
+                req.dispatch_time = s.clock()
             req.prefill_instance = p_eng.name
             req.decode_instance = d_eng.name
             if s.prefill_chunk is None:
@@ -218,8 +222,9 @@ class PrefillFlightLoop:
             fl.handoff.poll_reads(s.repage_budget - repaged)
         if not fl.stream.done or fl.handoff.pending_reads():
             return None
-        meta = fl.handoff.finalize(fl.stream.first_token,
-                                   fl.stream.tail_package())
+        with span("pd.handoff.finalize", req=fl.req.req_id):
+            meta = fl.handoff.finalize(fl.stream.first_token,
+                                       fl.stream.tail_package())
         return meta["first_token"]
 
 
@@ -433,12 +438,13 @@ class GlobalScheduler:
     def step(self) -> List[Tuple[Request, int]]:
         """One scheduler tick: pump the P-side flight loop, then the D-side
         decode loop. Returns emitted (request, token) pairs."""
-        self._handle_failures()
-        # advance the wire: async connectors progress in-flight reads here
-        self.pipeline.transfer.tick()
-        emitted: List[Tuple[Request, int]] = []
-        self.prefill_loop.pump(emitted)
-        self.decode_loop.pump(emitted)
+        with span("pd.tick"):
+            self._handle_failures()
+            # advance the wire: async connectors progress in-flight reads here
+            self.pipeline.transfer.tick()
+            emitted: List[Tuple[Request, int]] = []
+            self.prefill_loop.pump(emitted)
+            self.decode_loop.pump(emitted)
         return emitted
 
     def _finish(self, req: Request, engine: Engine,

@@ -1,5 +1,7 @@
 """Shared tiny-model fixtures. Tests run on the plain 1-device CPU backend —
 the 512-device dry-run is exercised only via repro.launch.dryrun."""
+import re
+
 import numpy as np
 import pytest
 
@@ -53,6 +55,14 @@ TINY_FAMILIES = {
     "vlm": tiny("vlm", family="vlm", num_kv_heads=2,
                 frontend=FrontendConfig(kind="vision", num_patches=4)),
 }
+
+
+def hlo_without_metadata(hlo: str) -> str:
+    """Compiled HLO text without op metadata and the source tables it
+    points to: what the program computes, not where it came from."""
+    lines = [line for line in hlo.splitlines() if not re.match(
+        r"(\d+ |FileNames|FunctionNames|FileLocations|StackFrames)", line)]
+    return re.sub(r",? metadata=\{[^}]*\}", "", "\n".join(lines))
 
 
 @pytest.fixture(scope="session")

@@ -216,6 +216,9 @@ def test_cluster_2p2d_token_exact_vs_single_process():
     assert sum(rt.stats.p_dispatches.values()) == len(reqs)
     assert sum(rt.stats.d_dispatches.values()) == len(reqs)
     assert len(rt.stats.d_dispatches) == 2      # both Ds served work
+    # the dispatch stamp splits each TTFT into queue wait and flight
+    for r in reqs:
+        assert r.arrival_time <= r.dispatch_time <= r.first_token_time
     after = _shm_files()
     if before is not None:
         assert after - before == set()

@@ -135,4 +135,4 @@ def test_engine_stats_accumulate(params):
     sched.run(_reqs(2), max_ticks=200)
     assert p.stats.prefill_tokens > 0
     assert d.stats.decode_tokens > 0
-    assert d.stats.decode_seconds > 0
+    assert 0 < d.stats.decode_steps <= d.stats.decode_tokens

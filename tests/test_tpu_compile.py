@@ -6,6 +6,7 @@ program larger than the chip). Nothing runs: these are compiles only.
 The topology is described inside a fixture (never at import), so every
 test worker collects the same tests and only the one given this file
 loads the TPU compiler."""
+import contextlib
 from functools import partial
 
 import jax
@@ -22,6 +23,7 @@ from repro.kernels import paged_attention as _pa
 from repro.models import model as M
 from repro.serving.engine import page_specs_for
 from repro.serving.paged_cache import KVPageSpec
+from tests.conftest import hlo_without_metadata
 
 HBM_BYTES = 16 * 2**30                     # one TPU v5e chip
 
@@ -143,3 +145,34 @@ def test_qwen3_4b_decode_step_fits_one_chip(one_chip):
     pool = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(caches))
     assert ma.alias_size_in_bytes >= pool      # the pools update in place
     assert total <= HBM_BYTES, (total, ma)
+
+
+def test_decode_step_scopes_change_metadata_only(one_chip, monkeypatch):
+    """The attention / mlp / lm_head scopes reach the chip's optimised
+    decode program as op metadata and change nothing else in it (two
+    layers at Qwen3-4B widths)."""
+    cfg = get_config("qwen3-4b").with_(num_layers=2)
+    specs = page_specs_for(cfg, 8, "nbhd", "bfloat16")
+    b, blocks, per_seq = 4, 64, 16
+    place = lambda t: jax.tree.map(                          # noqa: E731
+        lambda x: _sds(one_chip, x.shape, x.dtype), t)
+    args = (place(M.abstract_params(cfg)),
+            _sds(one_chip, (b, 1), "int32"), _sds(one_chip, (b,), "int32"),
+            _sds(one_chip, (b, per_seq), "int32"),
+            _sds(one_chip, (b,), "int32"), _sds(one_chip, (b,), "int32"),
+            place(jax.eval_shape(
+                lambda: M.init_paged_caches(cfg, specs, blocks, batch=b))))
+
+    def compiled_text():
+        fn = jax.jit(lambda p, t, sl, bt, wb, ws, c: M.decode_step_paged(
+            p, cfg, t, sl, bt, wb, ws, c, specs), donate_argnums=(6,))
+        return fn.lower(*args).compile().as_text()
+
+    scoped = compiled_text()
+    for scope in ("attention", "mlp", "lm_head"):
+        assert f"/{scope}/" in scoped, scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = compiled_text()
+    assert "/attention/" not in plain
+    assert hlo_without_metadata(scoped) == hlo_without_metadata(plain)
