@@ -47,20 +47,21 @@ def main() -> list:
 
     for (b, h, kv, d, bs, pages) in [(4, 8, 2, 64, 16, 8),
                                      (8, 16, 8, 128, 16, 16)]:
-        n = b * pages + 1
+        n, layers, layer = b * pages + 1, 2, 1
         q = jax.random.normal(ks[0], (b, h, d), jnp.bfloat16)
-        kp = jax.random.normal(ks[1], (n, bs, kv, d), jnp.bfloat16)
-        vp = jax.random.normal(ks[2], (n, bs, kv, d), jnp.bfloat16)
+        kp = jax.random.normal(ks[1], (layers, n, bs, kv, d), jnp.bfloat16)
+        vp = jax.random.normal(ks[2], (layers, n, bs, kv, d), jnp.bfloat16)
         table = jnp.asarray(
             np.random.default_rng(0).permutation(n - 1)[:b * pages]
             .reshape(b, pages) + 1, jnp.int32)
         lens = jnp.full((b,), bs * pages - 3, jnp.int32)
-        t_ref = _t(ref.paged_attention_ref, q, kp, vp, table, lens)
-        got = ops.paged_attention(q, kp, vp, table, lens,
+        t_ref = _t(ref.paged_attention_ref, q, kp[layer], vp[layer], table,
+                   lens)
+        got = ops.paged_attention(q, kp, vp, jnp.int32(layer), table, lens,
                                   force_interpret=True)
         err = float(jnp.max(jnp.abs(
             got.astype(jnp.float32) -
-            ref.paged_attention_ref(q, kp, vp, table, lens)
+            ref.paged_attention_ref(q, kp[layer], vp[layer], table, lens)
             .astype(jnp.float32))))
         name = "paged_attention(decode)"
         print(f"{name:34s} b{b} h{h}/{kv} {pages}p×{bs} d{d:<3d} "

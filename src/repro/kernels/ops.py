@@ -40,13 +40,26 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                                interpret=_interpret(force_interpret))
 
 
+def paged_decode_path(spec: KVPageSpec) -> str:
+    """The decode attention a paged pool takes: ``"paged_kernel"`` (the
+    length-bounded Pallas kernel) on the TPU backend for a float ``nbhd``
+    pool whose pages the chip can copy whole — KV heads a multiple of 8
+    and head_dim a multiple of 128, Mosaic's (8, 128) tiling — else
+    ``"ref"`` (``paged_cache.paged_attention_ref``)."""
+    if (jax.default_backend() == "tpu" and spec.layout == "nbhd"
+            and spec.dtype in ("bfloat16", "float32")
+            and spec.kv_heads % 8 == 0 and spec.head_dim % 128 == 0):
+        return "paged_kernel"
+    return "ref"
+
+
 @partial(jax.jit, static_argnames=("window", "force_interpret"))
-def paged_attention(q, k_pool, v_pool, block_table, seq_lens, *,
-                    window: int = 0,
-                    force_interpret: bool = False):
-    """Decode attention over paged pools. q: (B,H,d); pools (N,bs,KV,d)."""
-    return _pa.paged_attention(q, k_pool, v_pool, block_table, seq_lens,
-                               window=window,
+def paged_attention(q, k_pool, v_pool, layer, block_table, seq_lens, *,
+                    window: int = 0, force_interpret: bool = False):
+    """Decode attention of pool layer ``layer`` over the pages below each
+    slot's length. q: (B,H,d); pools stacked (L,N,bs,KV,d)."""
+    return _pa.paged_attention(q, k_pool, v_pool, layer, block_table,
+                               seq_lens, window=window,
                                interpret=_interpret(force_interpret))
 
 

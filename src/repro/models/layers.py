@@ -376,24 +376,33 @@ def attention_decode(p: Params, cfg: ModelConfig, x: jax.Array,
 # --------------------------------------------------------------------------- #
 def attention_decode_paged(p: Params, cfg: ModelConfig, x: jax.Array,
                            positions: jax.Array, pcache: Dict[str, jax.Array],
-                           block_table: jax.Array, seq_lens: jax.Array,
-                           write_blocks: jax.Array, write_slots: jax.Array,
-                           spec, window: int = 0
+                           layer: jax.Array, block_table: jax.Array,
+                           seq_lens: jax.Array, write_blocks: jax.Array,
+                           write_slots: jax.Array, spec, window: int = 0
                            ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Single-token decode against paged pools.
 
-    x: (B,1,d); positions: (B,1) == old seq_lens; block_table: (B,maxb);
-    seq_lens: (B,) lengths BEFORE this token; write_blocks/slots: (B,).
+    x: (B,1,d); positions: (B,1) == old seq_lens; pcache: the group's
+    stacked pools (L, N, ...), read and written at pool ``layer`` (int32
+    scalar) in place; block_table: (B,maxb); seq_lens: (B,) lengths
+    BEFORE this token; write_blocks/slots: (B,). The attention is the
+    length-bounded kernel or the reference gather, as
+    ``ops.paged_decode_path`` chooses for ``spec``.
     """
+    from repro.kernels import ops
     from repro.serving import paged_cache as PC
     q, k, v = _project_qkv(p, cfg, x, positions)
     k_pool = PC.append_token(spec, pcache["k_pool"], write_blocks, write_slots,
-                             k[:, 0])
+                             k[:, 0], layer)
     v_pool = PC.append_token(spec, pcache["v_pool"], write_blocks, write_slots,
-                             v[:, 0])
+                             v[:, 0], layer)
     new_lens = seq_lens + 1
-    out = PC.paged_attention_ref(q, k_pool, v_pool, block_table, new_lens,
-                                 spec, window=window)
+    if ops.paged_decode_path(spec) == "paged_kernel":
+        out = ops.paged_attention(q[:, 0], k_pool, v_pool, layer, block_table,
+                                  new_lens, window=window)[:, None]
+    else:
+        out = PC.paged_attention_ref(q, k_pool, v_pool, block_table, new_lens,
+                                     spec, window=window, layer=layer)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return out, {"k_pool": k_pool, "v_pool": v_pool}
 

@@ -301,12 +301,29 @@ def _apply_layer_decode(p, cfg: ModelConfig, g: Group, kind: str, x,
 # --------------------------------------------------------------------------- #
 # Group scan
 # --------------------------------------------------------------------------- #
+def _layer_of(tree, li):
+    """Layer ``li`` of a group's stacked caches."""
+    return jax.tree.map(
+        lambda buf: jax.lax.dynamic_index_in_dim(buf, li, 0, keepdims=False),
+        tree)
+
+
+def _with_layer(tree, new, li):
+    """A group's stacked caches with layer ``li`` replaced by ``new``."""
+    return jax.tree.map(
+        lambda buf, n: jax.lax.dynamic_update_index_in_dim(
+            buf, n.astype(buf.dtype), li, 0), tree, new)
+
+
 def _scan_group(gp, cfg: ModelConfig, g: Group, x, apply_pos, caches_g,
-                remat: bool):
+                remat: bool, stacked: bool = False):
     """Scan a group over its ``count`` repetitions.
 
     gp: tuple(len(kinds)) of stacked params; caches_g same structure or None.
-    apply_pos(p_i, kind_i, x, cache_i) -> (x, new_cache_i)
+    apply_pos(p_i, kind_i, x, cache_i) -> (x, new_cache_i), or with
+    ``stacked`` apply_pos(p_i, kind_i, x, stacked_cache_i, layer) ->
+    (x, new_stacked_cache_i): it reads and writes its own layer, so a
+    kernel can address a layer's pool inside the stack.
 
     Caches ride in the scan CARRY (sliced / written back per layer with
     dynamic-(index|update)-slice) rather than as scan xs/ys: the carry is
@@ -332,13 +349,12 @@ def _scan_group(gp, cfg: ModelConfig, g: Group, x, apply_pos, caches_g,
         xx = _constrain(xx)
         new_caches = []
         for i, kind in enumerate(g.kinds):
-            c_i = jax.tree.map(
-                lambda buf: jax.lax.dynamic_index_in_dim(
-                    buf, li, 0, keepdims=False), caches[i])
-            xx, nc = apply_pos(ps[i], kind, xx, c_i)
-            new_caches.append(jax.tree.map(
-                lambda buf, n: jax.lax.dynamic_update_index_in_dim(
-                    buf, n.astype(buf.dtype), li, 0), caches[i], nc))
+            if stacked:
+                xx, nc = apply_pos(ps[i], kind, xx, caches[i], li)
+            else:
+                xx, nc = apply_pos(ps[i], kind, xx, _layer_of(caches[i], li))
+                nc = _with_layer(caches[i], nc, li)
+            new_caches.append(nc)
         return (xx, tuple(new_caches), li + 1), None
 
     if remat:
@@ -532,39 +548,49 @@ def init_paged_caches(cfg: ModelConfig, specs: Dict[str, Any],
 
 
 def _apply_layer_decode_paged(p, cfg: ModelConfig, g: Group, kind: str, x,
-                              positions, cache, block_table, seq_lens,
+                              positions, cache, li, block_table, seq_lens,
                               write_blocks, write_slots, specs):
+    """One layer of the paged decode step; ``cache`` is the group's
+    stacked caches of this position, read and written at layer ``li``."""
     if kind == SSD:
         h, st = L.ssd_decode(p["ssd"], cfg,
-                             L.rms_norm(p["norm"], x, cfg.norm_eps), cache)
-        return x + h, st
+                             L.rms_norm(p["norm"], x, cfg.norm_eps),
+                             _layer_of(cache, li))
+        return x + h, _with_layer(cache, st, li)
     if kind == RECURRENT:
         h, st = L.rglru_decode(p["rglru"], cfg,
-                               L.rms_norm(p["norm1"], x, cfg.norm_eps), cache)
+                               L.rms_norm(p["norm1"], x, cfg.norm_eps),
+                               _layer_of(cache, li))
         x = x + h
         x = x + L.swiglu_mlp(p["mlp"], L.rms_norm(p["norm2"], x, cfg.norm_eps))
-        return x, st
+        return x, _with_layer(cache, st, li)
     win = _window(cfg)
     h = L.rms_norm(p["norm1"], x, cfg.norm_eps)
-    with jax.named_scope("attention"):
-        if cfg.attention_kind == "mla":
+    if cfg.attention_kind == "mla":
+        pools = {k: cache[k] for k in ("ckv_pool", "kpe_pool")}
+        with jax.named_scope("attention"):
             out, new_pools = L.mla_decode_paged(
-                p["attn"], cfg, h, positions, cache, block_table, seq_lens,
-                write_blocks, write_slots, specs["ckv"], specs["kpe"])
-        else:
+                p["attn"], cfg, h, positions, _layer_of(pools, li),
+                block_table, seq_lens, write_blocks, write_slots,
+                specs["ckv"], specs["kpe"])
+        new_pools = _with_layer(pools, new_pools, li)
+    else:
+        # the stacked pools go to the attention whole: the kernel reads
+        # layer li in HBM, with no per-layer copy of a pool
+        with jax.named_scope("attention"):
             out, new_pools = L.attention_decode_paged(
-                p["attn"], cfg, h, positions, cache, block_table, seq_lens,
-                write_blocks, write_slots, specs["kv"], window=win)
+                p["attn"], cfg, h, positions, cache, li, block_table,
+                seq_lens, write_blocks, write_slots, specs["kv"], window=win)
     x = x + out
     new_cache = dict(new_pools)
     if g.cross:
         hx = L.rms_norm(p["norm_x"], x, cfg.norm_eps)
         x = x + L.cross_attention(p["cross"], cfg, hx,
-                                  (cache["cross_k"], cache["cross_v"]),
-                                  cache["mem_len"])
-        new_cache.update({"cross_k": cache["cross_k"],
-                          "cross_v": cache["cross_v"],
-                          "mem_len": cache["mem_len"]})
+                                  (_layer_of(cache["cross_k"], li),
+                                   _layer_of(cache["cross_v"], li)),
+                                  _layer_of(cache["mem_len"], li))
+        new_cache.update({k: cache[k] for k in ("cross_k", "cross_v",
+                                                "mem_len")})
     h2 = L.rms_norm(p["norm2"], x, cfg.norm_eps)
     x = x + _mlp_apply(p["mlp"], cfg, g.moe, h2)
     return x, new_cache
@@ -584,12 +610,12 @@ def decode_step_paged(params, cfg: ModelConfig, tokens: jax.Array,
     groups = block_groups(cfg)
     new_caches = []
     for gi, g in enumerate(groups):
-        def apply_pos(p_i, kind, xx, c_i, _g=g):
+        def apply_pos(p_i, kind, xx, c_i, li, _g=g):
             return _apply_layer_decode_paged(
-                p_i, cfg, _g, kind, xx, positions, c_i, block_table,
+                p_i, cfg, _g, kind, xx, positions, c_i, li, block_table,
                 seq_lens, write_blocks, write_slots, specs)
         x, nc = _scan_group(params["groups"][gi], cfg, g, x, apply_pos,
-                            caches[gi], remat=False)
+                            caches[gi], remat=False, stacked=True)
         new_caches.append(nc)
     return lm_logits(params, cfg, x), tuple(new_caches)
 

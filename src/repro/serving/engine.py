@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig, PrefillCapabilities
+from repro.kernels import ops
 from repro.models import model as M
 from repro.serving.paged_cache import BlockAllocator, KVPageSpec
 from repro.serving.prefix_cache import HostPrefixStore, PrefixStore, hashing
@@ -88,6 +89,8 @@ class EngineStats:
     prefill_chunks: int = 0         # compute chunks (1 per monolithic prefill)
     decode_steps: int = 0
     decode_tokens: int = 0
+    decode_kv_pages: int = 0        # KV pages below the active slots'
+    #                                 lengths, summed over decode steps
     prefill_seconds: float = 0.0
     failures_injected: int = 0
     prefix_cached_tokens: int = 0   # prompt tokens replayed from the P-side
@@ -586,6 +589,9 @@ class Engine:
         self.specs = page_specs_for(cfg, vendor.block_size, vendor.layout,
                                     vendor.kv_dtype)
         self.block_size = vendor.block_size
+        # the decode attention this engine's pool takes (span attribute)
+        self.decode_attention = (ops.paged_decode_path(self.specs["kv"])
+                                 if "kv" in self.specs else "ref")
         self.max_blocks_per_seq = -(-max_seq_len // vendor.block_size)
         self.allocator = BlockAllocator(num_blocks)
         self.allocator.allocate("__scratch__", 1)   # trash page for idle slots
@@ -979,7 +985,10 @@ class Engine:
                   if r is not None and self.slot_ready[i]]
         if not active:
             return []
-        with span("pd.decode.step", batch=len(active)):
+        kv_pages = int((-(-(self.seq_lens[active] + 1)
+                          // self.block_size)).sum())
+        with span("pd.decode.step", batch=len(active),
+                  attn=self.decode_attention, kv_pages=kv_pages):
             with span("pd.decode.prepare"):
                 write_slots = self.seq_lens % self.block_size
                 write_block_idx = self.seq_lens // self.block_size
@@ -1010,6 +1019,7 @@ class Engine:
                     out.append((slot, req, int(tok)))
         self.stats.decode_steps += 1
         self.stats.decode_tokens += len(active)
+        self.stats.decode_kv_pages += kv_pages
         return out
 
     # ------------------------------------------------------------------ #

@@ -132,33 +132,42 @@ def scatter_sequence_overlay(spec: KVPageSpec, pool: jax.Array,
 
 
 def append_token(spec: KVPageSpec, pool: jax.Array, block_ids: jax.Array,
-                 slot: jax.Array, kv_tok: jax.Array) -> jax.Array:
+                 slot: jax.Array, kv_tok: jax.Array,
+                 layer: Optional[jax.Array] = None) -> jax.Array:
     """Write one token's KV per sequence during decode.
 
     block_ids: (B,) physical block of each seq's current page;
-    slot: (B,) offset within the block; kv_tok: (B, kv, hd)."""
+    slot: (B,) offset within the block; kv_tok: (B, kv, hd). With
+    ``layer``, ``pool`` stacks every layer's pool (L, N, ...) and the
+    token lands in pool ``layer``, written in place in the stack."""
     kv_tok = kv_tok.astype(spec.jdtype)
-    if spec.layout == "nbhd":
-        return pool.at[block_ids, slot].set(kv_tok)
-    if spec.layout == "nhbd":
-        return pool.at[block_ids, :, slot].set(kv_tok)
-    return pool.at[block_ids, :, :, slot].set(kv_tok)      # nhdb
+    at = (block_ids,) + {"nbhd": (slot,), "nhbd": (slice(None), slot),
+                         "nhdb": (slice(None), slice(None), slot)}[spec.layout]
+    if layer is not None:
+        at = (layer,) + at
+    return pool.at[at].set(kv_tok)
 
 
 def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                         block_table: jax.Array, seq_lens: jax.Array,
                         spec: KVPageSpec, scale: Optional[float] = None,
-                        window: int = 0) -> jax.Array:
-    """Decode attention against paged KV. Reference (jnp gather) path.
+                        window: int = 0,
+                        layer: Optional[jax.Array] = None) -> jax.Array:
+    """Decode attention against paged KV. Reference (jnp gather) path:
+    gathers every slot's whole block table.
 
     q: (B, 1, H, hd); block_table: (B, max_blocks); seq_lens: (B,) lengths
     INCLUDING the current token. ``window`` > 0 masks a sliding window.
-    Returns (B, 1, H, hd)."""
+    With ``layer``, the pools stack every layer's (L, N, ...) and pool
+    ``layer`` is read. Returns (B, 1, H, hd)."""
     b, _, h, hd = q.shape
     max_b = block_table.shape[1]
     kv = spec.kv_heads
-    kp = pages_to_canonical(spec, k_pool[block_table.reshape(-1)])
-    vp = pages_to_canonical(spec, v_pool[block_table.reshape(-1)])
+    ids = block_table.reshape(-1)
+    if layer is not None:
+        ids = (layer, ids)
+    kp = pages_to_canonical(spec, k_pool[ids])
+    vp = pages_to_canonical(spec, v_pool[ids])
     s_max = max_b * spec.block_size
     k = kp.reshape(b, s_max, kv, hd)
     v = vp.reshape(b, s_max, kv, hd)

@@ -1,5 +1,6 @@
 """Shared tiny-model fixtures. Tests run on the plain 1-device CPU backend —
 the 512-device dry-run is exercised only via repro.launch.dryrun."""
+import gc
 import re
 
 import numpy as np
@@ -63,6 +64,16 @@ def hlo_without_metadata(hlo: str) -> str:
     lines = [line for line in hlo.splitlines() if not re.match(
         r"(\d+ |FileNames|FunctionNames|FileLocations|StackFrames)", line)]
     return re.sub(r",? metadata=\{[^}]*\}", "", "\n".join(lines))
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_programs_from_earlier_modules():
+    """Each test module starts with no compiled program that an earlier
+    module left alive. A profiler trace lists every live program, so what
+    a module reads from a trace must not depend on which modules ran
+    before it in the same worker."""
+    jax.clear_caches()
+    gc.collect()
 
 
 @pytest.fixture(scope="session")

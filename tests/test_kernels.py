@@ -6,7 +6,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from jax.experimental.pallas import tpu as pltpu
+
 from repro.kernels import ops, ref
+from repro.kernels import paged_attention as _pa
 from repro.serving.paged_cache import KVPageSpec
 
 ATOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
@@ -38,6 +41,39 @@ def test_flash_attention_sweep(b, h, kv, sq, skv, d, dtype, causal, window):
                                atol=ATOL[dtype])
 
 
+def _paged_case(b, h, kv, d, bs, pages, dtype, seq_lens, *, layers=3,
+                layer=1, window=0, idle=(),
+                interpret=None):
+    """The kernel on pool ``layer`` of a stack of ``layers`` pools against
+    the reference on that layer alone. Slots in ``idle`` hold the scratch
+    block 0 in every table entry, as the engine's idle slots do. With
+    ``interpret`` (``pltpu.InterpretParams``) the kernel runs in the TPU
+    interpreter, which simulates its copies and semaphores."""
+    n_blocks = b * pages + 1
+    ks = jax.random.split(jax.random.key(hash((b, h, d)) % 2**31), 4)
+    q = _rand(ks[0], (b, h, d), dtype)
+    k_pool = _rand(ks[1], (layers, n_blocks, bs, kv, d), dtype)
+    v_pool = _rand(ks[2], (layers, n_blocks, bs, kv, d), dtype)
+    rng = np.random.default_rng(0)
+    table = rng.permutation(n_blocks - 1)[:b * pages].reshape(b, pages) + 1
+    table[list(idle)] = 0
+    table = jnp.asarray(table, jnp.int32)
+    seq_lens = jnp.asarray(seq_lens, jnp.int32)
+    if interpret is None:
+        got = ops.paged_attention(q, k_pool, v_pool, jnp.int32(layer), table,
+                                  seq_lens, window=window,
+                                  force_interpret=True)
+    else:
+        got = _pa.paged_attention(q, k_pool, v_pool, jnp.int32(layer), table,
+                                  seq_lens, window=window,
+                                  interpret=interpret)
+    want = ref.paged_attention_ref(q, k_pool[layer], v_pool[layer], table,
+                                   seq_lens, window=window)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=ATOL[dtype])
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("b,h,kv,d,bs,pages", [
     (2, 4, 4, 32, 8, 4),
@@ -46,22 +82,96 @@ def test_flash_attention_sweep(b, h, kv, sq, skv, d, dtype, causal, window):
 ])
 @pytest.mark.parametrize("window", [0, 11])
 def test_paged_attention_sweep(b, h, kv, d, bs, pages, dtype, window):
-    n_blocks = b * pages + 1
-    ks = jax.random.split(jax.random.key(hash((b, h, d)) % 2**31), 4)
-    q = _rand(ks[0], (b, h, d), dtype)
-    k_pool = _rand(ks[1], (n_blocks, bs, kv, d), dtype)
-    v_pool = _rand(ks[2], (n_blocks, bs, kv, d), dtype)
-    rng = np.random.default_rng(0)
-    table = rng.permutation(n_blocks - 1)[:b * pages].reshape(b, pages) + 1
-    table = jnp.asarray(table, jnp.int32)
-    seq_lens = jnp.asarray(rng.integers(1, bs * pages + 1, b), jnp.int32)
-    got = ops.paged_attention(q, k_pool, v_pool, table, seq_lens,
-                              window=window, force_interpret=True)
-    want = ref.paged_attention_ref(q, k_pool, v_pool, table, seq_lens,
-                                   window=window)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               atol=ATOL[dtype])
+    seq_lens = np.random.default_rng(0).integers(1, bs * pages + 1, b)
+    _paged_case(b, h, kv, d, bs, pages, dtype, seq_lens, window=window)
+
+
+@pytest.fixture
+def block_rows(monkeypatch):
+    """Sets the kernel's compute block to a number of K rows, so that a
+    small pool spans several blocks. The jitted wrapper reads the block at
+    trace time: its traces are dropped on either side of the change."""
+    def set_rows(rows):
+        ops.paged_attention.clear_cache()
+        monkeypatch.setattr(_pa, "BLOCK_ROWS", rows)
+    yield set_rows
+    ops.paged_attention.clear_cache()
+
+
+# (group size, block size, lengths, window, idle slots); 4 slots of 6
+# pages, compute blocks of 2 pages, so a slot spans up to 3 blocks
+_CASES = {
+    "edges_bs8": (4, 8, [1, 7, 8, 9], 0, ()),
+    "edges_bs16": (4, 16, [1, 15, 16, 17], 0, ()),
+    "block_multiples": (4, 8, [16, 32, 48, 17], 0, ()),
+    "full_capacity": (4, 8, [48, 48, 47, 1], 0, ()),
+    "idle_next_to_busy": (4, 8, [1, 40, 1, 23], 0, (0, 2)),
+    "window_mid_page": (4, 8, [40, 9, 26, 48], 11, ()),
+    "group_1": (1, 16, [5, 96, 33, 64], 0, ()),
+    "group_8": (8, 8, [2, 30, 48, 12], 5, ()),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_paged_attention_lengths(case, dtype, block_rows):
+    """Lengths at page and compute-block edges, idle slots, a window that
+    starts mid-page, group sizes 1/4/8 and block sizes 8/16: the
+    length-bounded loop reads exactly the pages the reference masks to."""
+    grp, bs, seq_lens, window, idle = _CASES[case]
+    kv = 2
+    block_rows(2 * bs * kv)
+    _paged_case(4, kv * grp, kv, 32, bs, 6, dtype, seq_lens, window=window,
+                idle=idle)
+
+
+@pytest.mark.parametrize("dma", ["on_wait", "eager"])
+@pytest.mark.parametrize("case", ["idle_next_to_busy", "window_mid_page",
+                                  "group_8"])
+def test_paged_attention_copies(case, dma, block_rows):
+    """In the TPU interpreter, with scratch memory filled with NaN and each
+    copy landing when it is waited for (or as soon as it starts): every
+    block is waited for before it is read, the double buffer is never read
+    stale, and rows past a slot's last page never reach the result."""
+    grp, bs, seq_lens, window, idle = _CASES[case]
+    kv = 2
+    block_rows(2 * bs * kv)
+    _paged_case(4, kv * grp, kv, 32, bs, 6, jnp.float32, seq_lens,
+                window=window, idle=idle,
+                interpret=pltpu.InterpretParams(uninitialized_memory="nan",
+                                                dma_execution_mode=dma))
+
+
+def test_paged_attention_stacked_layer_append(block_rows):
+    """The decode append writes pool ``layer`` of the stack and no other;
+    the kernel then reads that layer with the new token."""
+    from repro.serving import paged_cache as PC
+    layers, layer, b, kv, d, bs = 4, 2, 3, 2, 32, 8
+    spec = KVPageSpec(bs, "nbhd", "float32", kv, d)
+    ks = jax.random.split(jax.random.key(7), 5)
+    pools = [_rand(k, (layers, 10, bs, kv, d), jnp.float32) for k in ks[:2]]
+    table = jnp.asarray([[1, 2, 3], [4, 5, 6], [7, 8, 9]], jnp.int32)
+    old_lens = np.asarray([3, 8, 20])
+    blocks = table[np.arange(b), old_lens // bs]
+    slots = jnp.asarray(old_lens % bs, jnp.int32)
+    toks = [_rand(k, (b, kv, d), jnp.float32) for k in ks[2:4]]
+    new = [PC.append_token(spec, p, blocks, slots, t, jnp.int32(layer))
+           for p, t in zip(pools, toks)]
+    for p, n, t in zip(pools, new, toks):
+        others = np.asarray([i for i in range(layers) if i != layer])
+        np.testing.assert_array_equal(np.asarray(n[others]),
+                                      np.asarray(p[others]))
+        np.testing.assert_array_equal(
+            np.asarray(n[layer, blocks, slots]), np.asarray(t))
+    q = _rand(ks[4], (b, kv * 4, d), jnp.float32)
+    lens = jnp.asarray(old_lens + 1, jnp.int32)
+    block_rows(bs * kv)                     # one page per compute block
+    got = ops.paged_attention(q, new[0], new[1], jnp.int32(layer), table,
+                              lens, force_interpret=True)
+    want = ref.paged_attention_ref(q, new[0][layer], new[1][layer], table,
+                                   lens)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=ATOL[jnp.float32])
 
 
 @pytest.mark.parametrize("layout", ["nbhd", "nhbd", "nhdb"])

@@ -136,3 +136,5 @@ def test_engine_stats_accumulate(params):
     assert p.stats.prefill_tokens > 0
     assert d.stats.decode_tokens > 0
     assert 0 < d.stats.decode_steps <= d.stats.decode_tokens
+    # each decoding slot holds at least the page its next token lands in
+    assert d.stats.decode_kv_pages >= d.stats.decode_tokens

@@ -92,16 +92,33 @@ def test_repage_kernel_compiles(one_chip, compiled_kernels, block_size,
     assert _has_kernel(compiled)
 
 
-def test_paged_attention_compiles(one_chip):
-    b, h, kv, d, bs, pages, blocks = 8, 32, 8, 128, 16, 64, 1024
-    fn = jax.jit(partial(_pa.paged_attention, interpret=False))
+@pytest.mark.parametrize("b,bs,pages,blocks", [
+    (16, 8, 320, 1370),                     # the qwen3-4b.chat D engine
+    (8, 16, 64, 1024),                      # 16-token pages
+])
+@pytest.mark.parametrize("window", [0, 256])
+def test_paged_attention_compiles(one_chip, window, b, bs, pages, blocks):
+    """The length-bounded decode kernel over 36 stacked bf16 pools at
+    Qwen3-4B's KV widths: the qwen3-4b.chat cell's D engine (16 slots of
+    320 pages of 8 tokens) and a pool of 16-token pages."""
+    h, kv, d, layers = 32, 8, 128, 36
+    fn = jax.jit(partial(_pa.paged_attention, window=window,
+                         interpret=False))
+    pool = _sds(one_chip, (layers, blocks, bs, kv, d), "bfloat16")
     compiled = fn.lower(
-        _sds(one_chip, (b, h, d), "bfloat16"),
-        _sds(one_chip, (blocks, bs, kv, d), "bfloat16"),
-        _sds(one_chip, (blocks, bs, kv, d), "bfloat16"),
+        _sds(one_chip, (b, h, d), "bfloat16"), pool, pool,
+        _sds(one_chip, (), "int32"),
         _sds(one_chip, (b, pages), "int32"),
         _sds(one_chip, (b,), "int32")).compile()
     assert _has_kernel(compiled)
+
+
+@pytest.fixture
+def kernel_path(compiled_kernels, monkeypatch):
+    """The backend here is the CPU, on which the model takes the reference
+    decode attention; these compiles take the chip's path, the kernel."""
+    monkeypatch.setattr(ops, "paged_decode_path",
+                        lambda spec: "paged_kernel")
 
 
 @pytest.mark.parametrize("window", [0, 256])
@@ -116,10 +133,11 @@ def test_flash_attention_compiles(one_chip, window):
     assert _has_kernel(compiled)
 
 
-def test_qwen3_4b_decode_step_fits_one_chip(one_chip):
+def test_qwen3_4b_decode_step_fits_one_chip(one_chip, kernel_path):
     """The decode step chip_smoke.py serves — 36 layers at published
     widths, its batch and its D pool, pools donated as the engine donates
-    them — compiles and fits the chip's 16 GiB."""
+    them, attention in the paged kernel — compiles and fits the chip's
+    16 GiB."""
     cfg = get_config("qwen3-4b")
     specs = page_specs_for(cfg, S.D_VENDOR.block_size, S.D_VENDOR.layout,
                            S.D_VENDOR.kv_dtype)
@@ -145,12 +163,23 @@ def test_qwen3_4b_decode_step_fits_one_chip(one_chip):
     pool = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(caches))
     assert ma.alias_size_in_bytes >= pool      # the pools update in place
     assert total <= HBM_BYTES, (total, ma)
+    assert _has_kernel(compiled)
+    # the kernel reads each layer's pages inside the stack: no pool, per
+    # layer or stacked, is copied or sliced out whole
+    shape = f"{blocks},{S.D_VENDOR.block_size},{cfg.num_kv_heads},"
+    assert not [line for line in compiled.as_text().splitlines()
+                if shape in line and any(
+                    f" {op}(" in line for op in ("copy", "copy-start",
+                                                 "dynamic-slice"))]
 
 
-def test_decode_step_scopes_change_metadata_only(one_chip, monkeypatch):
+def test_decode_step_scopes_change_metadata_only(one_chip, kernel_path,
+                                                 monkeypatch):
     """The attention / mlp / lm_head scopes reach the chip's optimised
     decode program as op metadata and change nothing else in it (two
-    layers at Qwen3-4B widths)."""
+    layers at Qwen3-4B widths). The paged kernel's custom call sits under
+    ``attention``, and no instruction copies or slices a whole per-layer
+    pool out of the stack."""
     cfg = get_config("qwen3-4b").with_(num_layers=2)
     specs = page_specs_for(cfg, 8, "nbhd", "bfloat16")
     b, blocks, per_seq = 4, 64, 16
@@ -171,6 +200,10 @@ def test_decode_step_scopes_change_metadata_only(one_chip, monkeypatch):
     scoped = compiled_text()
     for scope in ("attention", "mlp", "lm_head"):
         assert f"/{scope}/" in scoped, scope
+    kernels = [line for line in scoped.splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    assert kernels and all("/attention/" in k for k in kernels), kernels
+    assert f"[{blocks},8,8,128]" not in scoped    # no per-layer pool
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     plain = compiled_text()

@@ -92,6 +92,7 @@ def test_spans_of_a_served_run(params, tmp_path):
     more = _reqs(2)
     for r in more:
         r.req_id = "u" + r.req_id
+    pages0 = d.stats.decode_kv_pages
     with jax.profiler.trace(str(tmp_path)):
         _sched(p, d).run(more, max_ticks=200)
     assert all(r.state == State.FINISHED for r in more)
@@ -106,6 +107,12 @@ def test_spans_of_a_served_run(params, tmp_path):
             assert 0 < args["tokens"] <= CHUNK
         if name == "pd.decode.step":
             assert 1 <= args["batch"] <= 2
+            # the CPU takes the reference; pages below the slots' lengths
+            assert args["attn"] == "ref"
+            assert args["batch"] <= args["kv_pages"] <= args["batch"] * 4
+    assert sum(e[3]["kv_pages"] for e in events
+               if e[0] == "pd.decode.step") == (
+                   d.stats.decode_kv_pages - pages0)
     # every request's handoff is spanned under its own id
     for rid in ids:
         assert {"pd.prefill.chunk", "pd.handoff.extract",
